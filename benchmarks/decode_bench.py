@@ -254,7 +254,6 @@ def bench_faults(gen_len: int, iters: int) -> dict:
        on both sides, best-of-N, GC fenced, alternating order) but the
        gate is the direct fraction, which is what survives at real cache
        sizes where burst compute dwarfs a slot memcpy."""
-    from repro.core.hlo_analysis import xla_cost_dict
     from repro.serving.bucketing import select_kv_bucket
     from repro.serving.engine import Request, ServingEngine
     from repro.serving.fault_inject import FaultPlan
@@ -299,7 +298,7 @@ def bench_faults(gen_len: int, iters: int) -> dict:
         lowered = ft._decode_n.lower(
             ft.params, ft.cache, jnp.asarray(ft.tokens), n=ft.decode_block,
             kv_bucket=bucket, rope_len=ft.rope_len, with_sentinel=ws)
-        costs[ws] = xla_cost_dict(lowered.compile())
+        costs[ws] = lowered.compile().cost_analysis()
     for key in ("flops", "bytes accessed"):
         a, b = costs[False].get(key, 0.0), costs[True].get(key, 0.0)
         if a > 0:

@@ -24,15 +24,6 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-def xla_cost_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalised across jax versions: older
-    releases return a one-element list of per-device dicts, newer ones the
-    dict itself (and either may be None)."""
-    xca = compiled.cost_analysis()
-    if isinstance(xca, (list, tuple)):
-        xca = xca[0] if xca else None
-    return dict(xca) if xca else {}
-
 
 DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,

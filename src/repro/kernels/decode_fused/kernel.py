@@ -10,10 +10,18 @@ batch row pulls the row's working set into VMEM once, performs
   conv window shift -> silu -> (projections) -> softplus(dt)
   h' = h * exp(dt*A) + dt * B * x      y = C . h' + D * x
 
-in-register, and writes back only the new window, new state, and y.
+in-register, and writes back only the new state and y (plus, for
+Mamba-1, the new window).
 
-Grid: (B,) — rows are independent; everything per-row fits VMEM
-comfortably (largest real shape: [H, P, N] f32 state, a few MB).
+The Mamba-2 step runs on a (B, H/hb) grid: one batch row and a block of
+``hb`` heads per step, so the [hb, P, N] f32 state block stays small in
+VMEM at real widths (h=80, p=64, n=128) and the state stream is
+pipelined.  Mosaic cannot reshape lanes into sublanes in-kernel, so the
+wrapper presents every operand in the layout the kernel computes in: the
+x channels as [.., H, P], B|C per group as one [.., 2N] row, the per-head
+vectors as [H, 1] columns.  The new conv window is the shifted input
+window; the wrapper forms it (a copy, no arithmetic).
+The Mamba-1 step runs one grid step per batch row.
 """
 from __future__ import annotations
 
@@ -23,9 +31,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-import jax.experimental.pallas.tpu as pltpu  # noqa: F401  (memory spaces)
-
-from repro.kernels.dispatch import tpu_compiler_params
+import jax.experimental.pallas.tpu as pltpu
 
 
 def _conv_step(conv_ref, x_ref, w_ref, b_ref):
@@ -39,28 +45,43 @@ def _conv_step(conv_ref, x_ref, w_ref, b_ref):
     return y, window
 
 
-def _m2_kernel(conv_ref, x_ref, w_ref, b_ref, dt_ref, dtb_ref, al_ref, d_ref,
-               ssm_ref, y_ref, nconv_ref, nssm_ref, *,
-               di: int, g: int, n: int, h: int, p: int):
-    xbc, window = _conv_step(conv_ref, x_ref, w_ref, b_ref)
-    # match the ref's dtype round-trip at the conv boundary
-    xbc = xbc.astype(x_ref.dtype).astype(jnp.float32)
-    xs = xbc[0, :di].reshape(h, p)
-    bm = xbc[0, di:di + g * n].reshape(g, n)
-    cm = xbc[0, di + g * n:].reshape(g, n)
-    dt = jax.nn.softplus(dt_ref[...].astype(jnp.float32)
-                         + dtb_ref[...].astype(jnp.float32).reshape(1, -1))
-    a = -jnp.exp(al_ref[...].astype(jnp.float32)).reshape(1, -1)  # [1, H]
-    da = jnp.exp(dt * a)                               # [1, H]
-    bh = jnp.repeat(bm, h // g, axis=0)                # [H, N]
-    ch = jnp.repeat(cm, h // g, axis=0)
-    upd = (dt.T * bh)[:, None, :] * xs[:, :, None]     # [H, P, N]
-    hnew = ssm_ref[0] * da.T[:, :, None] + upd
-    y = jnp.sum(hnew * ch[:, None, :], axis=-1)        # [H, P]
-    y = y + xs * d_ref[...].astype(jnp.float32).reshape(-1, 1)
+def _conv_silu(conv, x, w, b, dtype):
+    """Depthwise conv over the [K-1, ...] window + the new token ([1, ...]),
+    taps on the leading axis; silu, rounded to the input dtype like the
+    oracle's conv boundary."""
+    window = jnp.concatenate([conv.astype(jnp.float32),
+                              x.astype(jnp.float32)], axis=0)
+    y = jnp.sum(window * w.astype(jnp.float32), axis=0)
+    y = y + b.astype(jnp.float32)
+    y = y * jax.nn.sigmoid(y)                          # silu
+    return y.astype(dtype).astype(jnp.float32)
+
+
+def _m2_kernel(cx_ref, cbc_ref, xt_ref, xbc_ref, wx_ref, wbc_ref, bx_ref,
+               bbc_ref, dt_ref, dtb_ref, al_ref, d_ref, ssm_ref, y_ref,
+               nssm_ref, *, n: int):
+    dtype = xt_ref.dtype
+    xs = _conv_silu(cx_ref[0], xt_ref[...], wx_ref[...], bx_ref[...],
+                    dtype)                             # [hb, P]
+    bc = _conv_silu(cbc_ref[0, 0], xbc_ref[0, 0], wbc_ref[0], bbc_ref[0],
+                    dtype)                             # [1, 2N]
+    bm, cm = bc[:, :n], bc[:, n:]                      # [1, N] each
+    dt = jax.nn.softplus(dt_ref[0].astype(jnp.float32)
+                         + dtb_ref[...].astype(jnp.float32))   # [hb, 1]
+    a = -jnp.exp(al_ref[...].astype(jnp.float32))     # [hb, 1]
+    da = jnp.exp(dt * a)                               # [hb, 1]
+    upd = (dt * bm)[:, None, :] * xs[:, :, None]       # [hb, P, N]
+    hnew = ssm_ref[0] * da[:, :, None] + upd
+    y = jnp.sum(hnew * cm[None], axis=-1)              # [hb, P]
+    y = y + xs * d_ref[...].astype(jnp.float32)
     y_ref[0] = y.astype(y_ref.dtype)
     nssm_ref[0] = hnew
-    nconv_ref[0] = window[1:].astype(nconv_ref.dtype)
+
+
+def _head_block(heads_per_group: int) -> int:
+    """Heads per grid step: 8 (the f32 sublane tile) when a group's heads
+    split into 8s, else one group's heads — a block never spans groups."""
+    return 8 if heads_per_group % 8 == 0 else heads_per_group
 
 
 def mamba2_decode_fused_pallas(conv_state, ssm_state, xbc_t, conv_w, conv_b,
@@ -73,37 +94,61 @@ def mamba2_decode_fused_pallas(conv_state, ssm_state, xbc_t, conv_w, conv_b,
     g, n, p = n_groups, d_state, headdim
     di = c - 2 * g * n
     h = di // p
-    kern = functools.partial(_m2_kernel, di=di, g=g, n=n, h=h, p=p)
-    y, nconv, nssm = pl.pallas_call(
-        kern,
-        grid=(b,),
+    hb = _head_block(h // g)
+    grp = h // g // hb                                 # head blocks per group
+
+    def bc_groups(t):
+        """[..., 2*G*N] (B then C) -> [..., G, 2N] (each group's B|C row)."""
+        lead = t.shape[:-1]
+        t = t.reshape(*lead, 2, g, n)
+        t = jnp.moveaxis(t, -3, -2)
+        return t.reshape(*lead, g, 2 * n)
+
+    cx = conv_state[..., :di].reshape(b, km1, h, p)
+    cbc = jnp.moveaxis(bc_groups(conv_state[..., di:]), 2, 1)  # [B,G,K-1,2N]
+    xt = xbc_t[:, :di].reshape(b, h, p)
+    xbc = bc_groups(xbc_t[:, di:])[:, :, None, :]            # [B, G, 1, 2N]
+    wt = conv_w.T                                            # [K, C]
+    wx = wt[:, :di].reshape(k, h, p)
+    wbc = jnp.moveaxis(bc_groups(wt[:, di:]), 1, 0)          # [G, K, 2N]
+    bx = conv_b[:di].reshape(h, p)
+    bbc = bc_groups(conv_b[di:])[:, None, :]                 # [G, 1, 2N]
+    col = lambda v: v.reshape(h, 1)                          # noqa: E731
+
+    y, nssm = pl.pallas_call(
+        functools.partial(_m2_kernel, n=n),
+        grid=(b, h // hb),
         in_specs=[
-            pl.BlockSpec((1, k - 1, c), lambda bi: (bi, 0, 0)),
-            pl.BlockSpec((1, c), lambda bi: (bi, 0)),
-            pl.BlockSpec((c, k), lambda bi: (0, 0)),
-            pl.BlockSpec((c,), lambda bi: (0,)),
-            pl.BlockSpec((1, h), lambda bi: (bi, 0)),
-            pl.BlockSpec((h,), lambda bi: (0,)),
-            pl.BlockSpec((h,), lambda bi: (0,)),
-            pl.BlockSpec((h,), lambda bi: (0,)),
-            pl.BlockSpec((1, h, p, n), lambda bi: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, km1, hb, p), lambda bi, hi: (bi, 0, hi, 0)),
+            pl.BlockSpec((1, 1, km1, 2 * n),
+                         lambda bi, hi: (bi, hi // grp, 0, 0)),
+            pl.BlockSpec((1, hb, p), lambda bi, hi: (bi, hi, 0)),
+            pl.BlockSpec((1, 1, 1, 2 * n),
+                         lambda bi, hi: (bi, hi // grp, 0, 0)),
+            pl.BlockSpec((k, hb, p), lambda bi, hi: (0, hi, 0)),
+            pl.BlockSpec((1, k, 2 * n), lambda bi, hi: (hi // grp, 0, 0)),
+            pl.BlockSpec((hb, p), lambda bi, hi: (hi, 0)),
+            pl.BlockSpec((1, 1, 2 * n), lambda bi, hi: (hi // grp, 0, 0)),
+            pl.BlockSpec((1, hb, 1), lambda bi, hi: (bi, hi, 0)),
+            pl.BlockSpec((hb, 1), lambda bi, hi: (hi, 0)),
+            pl.BlockSpec((hb, 1), lambda bi, hi: (hi, 0)),
+            pl.BlockSpec((hb, 1), lambda bi, hi: (hi, 0)),
+            pl.BlockSpec((1, hb, p, n), lambda bi, hi: (bi, hi, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, h, p), lambda bi: (bi, 0, 0)),
-            pl.BlockSpec((1, k - 1, c), lambda bi: (bi, 0, 0)),
-            pl.BlockSpec((1, h, p, n), lambda bi: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, hb, p), lambda bi, hi: (bi, hi, 0)),
+            pl.BlockSpec((1, hb, p, n), lambda bi, hi: (bi, hi, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, p), xbc_t.dtype),
-            jax.ShapeDtypeStruct((b, k - 1, c),
-                                 jnp.result_type(conv_state.dtype,
-                                                 xbc_t.dtype)),
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(conv_state, xbc_t, conv_w, conv_b, dt_raw, dt_bias, A_log, D, ssm_state)
+    )(cx, cbc, xt, xbc, wx, wbc, bx, bbc, dt_raw.reshape(b, h, 1),
+      col(dt_bias), col(A_log), col(D), ssm_state)
+    nconv = jnp.concatenate([conv_state, xbc_t[:, None, :]], axis=1)[:, 1:]
     return y, nconv, nssm
 
 
@@ -172,7 +217,7 @@ def mamba1_decode_fused_pallas(conv_state, ssm_state, xi_t, conv_w, conv_b,
                                                  xi_t.dtype)),
             jax.ShapeDtypeStruct((b, di, n), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(conv_state, xi_t, conv_w, conv_b, x_proj, dt_proj, dt_bias, A_log, D,

@@ -6,10 +6,14 @@ Backends:
   * "pallas"    — Pallas TPU kernels (Mosaic). The deployment path on TPU.
   * "interpret" — Pallas kernels executed with interpret=True (CPU validation).
 
-Default: "ref" on CPU, "pallas" on TPU.  Override with set_backend(), the
-``use_backend`` context manager, or the REPRO_KERNEL_BACKEND environment
-variable (read once per call site: ``REPRO_KERNEL_BACKEND=interpret pytest``
-runs the whole suite through the Pallas interpreter).
+Default: "pallas" on TPU, "ref" elsewhere.  Off the TPU the
+REPRO_KERNEL_BACKEND environment variable overrides it (read once per call
+site: ``REPRO_KERNEL_BACKEND=interpret pytest`` runs the whole suite
+through the Pallas interpreter).  On a TPU the variable may only say
+"pallas": a chip run never silently serves through the interpreter or the
+reference.  ``set_backend()`` / the ``use_backend`` context manager select a
+backend explicitly in-process (e.g. a reference run to compare against).
+A failure to enumerate devices propagates; it is never read as "no TPU".
 """
 from __future__ import annotations
 
@@ -19,14 +23,6 @@ import threading
 import jax
 
 _LOCAL = threading.local()
-
-
-def tpu_compiler_params(**kwargs):
-    """jax renamed pltpu.TPUCompilerParams -> CompilerParams across versions;
-    build whichever this install provides."""
-    import jax.experimental.pallas.tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
 
 
 def decode_split_k():
@@ -66,13 +62,13 @@ def ring_buckets() -> bool:
 
 def default_backend() -> str:
     env = os.environ.get("REPRO_KERNEL_BACKEND")
-    if env:
-        return env
-    try:
-        plat = jax.devices()[0].platform
-    except Exception:  # pragma: no cover
-        plat = "cpu"
-    return "pallas" if plat == "tpu" else "ref"
+    if jax.devices()[0].platform == "tpu":
+        if env and env != "pallas":
+            raise RuntimeError(
+                f"REPRO_KERNEL_BACKEND={env!r} on a TPU: the chip serves "
+                "through the Pallas kernels only (unset it, or set 'pallas')")
+        return "pallas"
+    return env or "ref"
 
 
 def get_backend() -> str:

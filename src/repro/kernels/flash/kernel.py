@@ -36,8 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.dispatch import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -46,6 +44,7 @@ def _flash_kernel(qoff_ref, kvwrap_ref, q_ref, k_ref, v_ref, o_ref,
                   bq: int, bk: int, nk: int, causal: bool,
                   window: Optional[int], scale: float, kv_len: int,
                   ring_len: Optional[int]):
+    bi = pl.program_id(0)
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -56,7 +55,7 @@ def _flash_kernel(qoff_ref, kvwrap_ref, q_ref, k_ref, v_ref, o_ref,
         acc_s[...] = jnp.zeros_like(acc_s)
 
     # per-row query offset (chunked prefill); zeros for plain prefill
-    q_start = qi * bq + qoff_ref[0]
+    q_start = qi * bq + qoff_ref[bi]
     k_start = ki * bk
     # block-level skip: k block entirely in the future (causal) or entirely
     # out of the attention window
@@ -73,7 +72,7 @@ def _flash_kernel(qoff_ref, kvwrap_ref, q_ref, k_ref, v_ref, o_ref,
         # the cursor is dead.  Chunk-tail coverage keeps the causal skip
         # on its absolute positions.  A block may span both regions —
         # either live half forces it to run.
-        wrap = kvwrap_ref[0]
+        wrap = kvwrap_ref[bi]
         ring_live = jnp.logical_and(
             k_start < ring_len,
             jnp.logical_or(wrap >= window, k_start < wrap))
@@ -94,7 +93,7 @@ def _flash_kernel(qoff_ref, kvwrap_ref, q_ref, k_ref, v_ref, o_ref,
             kpos = jidx
             mask = jidx < kv_len
         else:
-            wrap = kvwrap_ref[0]
+            wrap = kvwrap_ref[bi]
             ring_pos = wrap - 1 - jnp.mod(wrap - 1 - jidx, window)
             tail_pos = wrap + (jidx - ring_len)
             kpos = jnp.where(jidx < ring_len, ring_pos, tail_pos)
@@ -170,10 +169,8 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
         kern,
         grid=(b, h, nq, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda bi, hi, qi, ki: (bi,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda bi, hi, qi, ki: (bi,),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, bk, d),
                          lambda bi, hi, qi, ki: (bi, hi // gsz, ki, 0)),
@@ -188,7 +185,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.dispatch import tpu_compiler_params
-
 
 def _scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, init_ref,
                  y_ref, final_ref, h_s, *, bs: int, ns: int):
@@ -85,7 +83,7 @@ def selective_scan_pallas(x, dt, A, Bm, Cm, D, *,
             jax.ShapeDtypeStruct((b, c, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bc, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, A, Bm, Cm, d2, initial_state)

@@ -1,5 +1,9 @@
 import os
+# a CPU compile tool: 512 host devices stand in for the production mesh, and
+# pinning the platform keeps it (and the per-cell children, which inherit
+# the environment) off any attached accelerator
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
@@ -25,7 +29,6 @@ import jax               # noqa: E402
 
 from repro.configs import ASSIGNED                          # noqa: E402
 from repro.core.config import SHAPES, TPU_V5E               # noqa: E402
-from repro.core.hlo_analysis import analyze_hlo_text, xla_cost_dict  # noqa: E402
 from repro.core.registry import get                         # noqa: E402
 from repro.core.roofline import model_flops                 # noqa: E402
 from repro.core.workload import applicable                  # noqa: E402
@@ -86,16 +89,16 @@ def run_cell(arch: str, shape: str, mesh_kind: str, out_dir: str,
         "argument_gb": ma.argument_size_in_bytes / 1e9,
         "output_gb": ma.output_size_in_bytes / 1e9,
         "temp_gb": ma.temp_size_in_bytes / 1e9,
-        "code_gb": getattr(ma, "generated_code_size_in_bytes", 0) / 1e9,
-        "alias_gb": getattr(ma, "alias_size_in_bytes", 0) / 1e9,
+        "code_gb": ma.generated_code_size_in_bytes / 1e9,
+        "alias_gb": ma.alias_size_in_bytes / 1e9,
         "hbm_gb": TPU_V5E.hbm_bytes / 1e9,
     }
     live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-            - getattr(ma, "alias_size_in_bytes", 0) + ma.temp_size_in_bytes)
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
     rec["memory"]["live_gb"] = live / 1e9
     rec["memory"]["fits"] = bool(live <= TPU_V5E.hbm_bytes)
 
-    xca = xla_cost_dict(compiled)
+    xca = compiled.cost_analysis()
     rec["xla_cost"] = {"flops": xca.get("flops", 0.0),
                        "bytes": xca.get("bytes accessed", 0.0)}
 

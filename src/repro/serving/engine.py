@@ -336,10 +336,14 @@ class ServingEngine:
         self.decode_block = decode_block
         kv_repeat = plan.kv_repeat if plan else 1
         self.cache = init_lm_cache(cfg, slots, max_seq, kv_repeat=kv_repeat)
+        # the burst's output cache replaces self.cache: donating the input
+        # lets XLA update KV/state in place instead of holding two copies
+        # (at published widths each is GBs of a 16 GB chip)
         self._decode_n = jax.jit(make_decode_tokens(cfg, plan),
                                  static_argnames=("n", "kv_bucket",
                                                   "rope_len",
-                                                  "with_sentinel"))
+                                                  "with_sentinel"),
+                                 donate_argnames=("cache",))
         self._scatter = jax.jit(_scatter_group)
         self.kv_repeat = kv_repeat
         self.chunk_size = chunk_size or min(256, max_seq)

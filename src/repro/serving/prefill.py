@@ -100,18 +100,21 @@ def _make_chunk_step(cfg: ModelConfig, plan: Optional[ShardingPlan] = None):
 
 # jitted chunk steps keyed by everything the closure actually depends on
 # (cfg plus the plan's kv_repeat/moe_groups, plus the REPRO_RING_BUCKETS
-# flag — it is read at TRACE time inside lm_prefill_chunk, so it must key
-# the cache or flipping the env after a first compile would silently
-# reuse the old trace): repeated chunked_prefill calls must reuse the
-# compiled program, not re-trace.  kv_bucket and rope_len are static
-# arguments: one compile per bucket-ladder rung actually touched
-# (rope_len is constant per serving deployment).
-_STEP_CACHE: Dict[Tuple[ModelConfig, int, int, bool], Any] = {}
+# flag and the kernel backend — both are read at TRACE time inside
+# lm_prefill_chunk, so they must key the cache or flipping either after a
+# first compile would silently reuse the old trace, e.g. a reference run
+# under ``dispatch.use_backend("ref")`` replaying the Pallas program):
+# repeated chunked_prefill calls must reuse the compiled program, not
+# re-trace.  kv_bucket and rope_len are static arguments: one compile per
+# bucket-ladder rung actually touched (rope_len is constant per serving
+# deployment).
+_STEP_CACHE: Dict[Tuple[ModelConfig, int, int, bool, str], Any] = {}
 
 
 def _jitted_chunk_step(cfg: ModelConfig, plan: Optional[ShardingPlan]):
     key = (cfg, plan.kv_repeat if plan else 1,
-           plan.moe_groups if plan else 1, kdispatch.ring_buckets())
+           plan.moe_groups if plan else 1, kdispatch.ring_buckets(),
+           kdispatch.get_backend())
     if key not in _STEP_CACHE:
         _STEP_CACHE[key] = jax.jit(_make_chunk_step(cfg, plan),
                                    static_argnames=("kv_bucket", "rope_len",

@@ -43,12 +43,11 @@ This module replaces the scalars with three layers:
   to that file as one JSON line carrying ``version`` + ``arch``;
   :func:`read_trace` rejects lines written by an incompatible schema.
 * **Operator attribution** — :func:`operator_costs` maps a compiled XLA
-  program to flop/byte totals (via the version-portable
-  :func:`repro.core.hlo_analysis.xla_cost_dict`) plus per-kernel-family
-  shares (gemm / ssm / norm / memory / arith / collective) from the
-  trip-count-corrected HLO walk — the paper's Table-style operator
-  breakdown, derived statically so benchmarks can report it without a
-  profiler.  The *measured* counterpart lives in
+  program to flop/byte totals (XLA's ``compiled.cost_analysis()``)
+  plus per-kernel-family shares (gemm / ssm / norm / memory / arith /
+  collective) from the trip-count-corrected HLO walk — the paper's
+  Table-style operator breakdown, derived statically so benchmarks can
+  report it without a profiler.  The *measured* counterpart lives in
   :mod:`repro.serving.profiler`.
 
 All timestamps come from the injected ``clock`` (the engine passes its
@@ -425,14 +424,14 @@ class Telemetry:
 def operator_costs(compiled) -> Dict[str, Any]:
     """Static operator-level attribution for one compiled XLA program:
     ``{"flops", "bytes", "by_class": {family: {flops, bytes, flop_share,
-    byte_share}}}``.  Totals come from the version-portable
-    :func:`repro.core.hlo_analysis.xla_cost_dict`; the per-family shares
+    byte_share}}}``.  Totals come from XLA's
+    ``compiled.cost_analysis()``; the per-family shares
     (gemm / ssm / norm / memory / arith / collective — the paper's
     operator taxonomy) from the trip-count-corrected HLO walk, which is
     what makes scanned-layer models attributable at all (XLA's aggregate
     counts a ``while`` body once regardless of trip count)."""
-    from repro.core.hlo_analysis import analyze_hlo_text, xla_cost_dict
-    xca = xla_cost_dict(compiled)
+    from repro.core.hlo_analysis import analyze_hlo_text
+    xca = compiled.cost_analysis()
     out: Dict[str, Any] = {"flops": float(xca.get("flops", 0.0)),
                            "bytes": float(xca.get("bytes accessed", 0.0)),
                            "by_class": {}}

@@ -20,7 +20,8 @@ from repro.core.config import AttnConfig, ModelConfig, SSMConfig
 from repro.kernels import dispatch
 from repro.models.lm import (decode_tokens, init_lm_cache, init_lm_params,
                              lm_prefill, lm_prefill_chunk)
-from repro.serving.prefill import chunked_prefill, supports_chunked_prefill
+from repro.serving.prefill import (_jitted_chunk_step, chunked_prefill,
+                                   supports_chunked_prefill)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -233,6 +234,19 @@ def test_chunk_parity_interpret_backend(arch):
                                rtol=2e-2, atol=2e-2)
     np.testing.assert_array_equal(np.asarray(cache["pos"]),
                                   np.asarray(ref_cache["pos"]))
+
+
+def test_chunk_step_cache_keys_on_backend():
+    """The kernel backend is read at trace time, so the jitted chunk step
+    is kept per backend: a reference run after a Pallas run in the same
+    process traces its own program instead of replaying the Pallas one."""
+    cfg = _cfgs()["mamba2"]
+    with dispatch.use_backend("interpret"):
+        interp = _jitted_chunk_step(cfg, None)
+    with dispatch.use_backend("ref"):
+        ref = _jitted_chunk_step(cfg, None)
+        assert _jitted_chunk_step(cfg, None) is ref
+    assert interp is not ref
 
 
 @pytest.mark.parametrize("arch", [

@@ -18,10 +18,9 @@ SCRIPT = textwrap.dedent("""
     from repro.checkpoint.ckpt import restore, save
 
     ckpt_dir = sys.argv[1]
-    at = getattr(jax.sharding, "AxisType", None)  # absent on older jax
-    kw = (lambda n: {"axis_types": (at.Auto,) * n}) if at else (lambda n: {})
-    mesh_a = jax.make_mesh((8,), ("model",), **kw(1))
-    mesh_b = jax.make_mesh((2, 4), ("data", "model"), **kw(2))
+    auto = jax.sharding.AxisType.Auto
+    mesh_a = jax.make_mesh((8,), ("model",), axis_types=(auto,))
+    mesh_b = jax.make_mesh((2, 4), ("data", "model"), axis_types=(auto,) * 2)
 
     # "train" on mesh A: params sharded 8-way on the last dim
     w = jnp.arange(16 * 64, dtype=jnp.float32).reshape(16, 64)

@@ -3,7 +3,7 @@ attribution via named_scope, and dot-FLOP accounting."""
 import jax
 import jax.numpy as jnp
 
-from repro.core.hlo_analysis import analyze_compiled, parse_hlo, xla_cost_dict
+from repro.core.hlo_analysis import analyze_compiled, parse_hlo
 
 
 def _compile(f, *specs):
@@ -29,7 +29,7 @@ def test_scan_trip_count_multiplied():
     assert abs(s1.flops - expected) / expected < 0.05
     assert abs(s1.flops - s2.flops) / expected < 0.05
     # XLA's own aggregate (known limitation): undercounts the scan body.
-    xla = xla_cost_dict(_compile(f_scan, x, w)).get("flops", 0)
+    xla = _compile(f_scan, x, w).cost_analysis().get("flops", 0)
     assert xla < 0.5 * expected
 
 

@@ -13,11 +13,7 @@ from repro.distributed.sharding import plan_sharding, zero1_rules
 def _mesh(multi_pod=False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     names = ("pod", "data", "model") if multi_pod else ("data", "model")
-    try:
-        return AbstractMesh(shape, names)
-    except TypeError:
-        # older jax: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(names, shape)))
+    return AbstractMesh(shape, names)
 
 
 @pytest.mark.parametrize("arch", ASSIGNED)
