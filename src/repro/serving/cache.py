@@ -215,7 +215,7 @@ def offload_slot(cache: Any, b: int, metrics=None,
     return blob
 
 
-def offload_slots(cache: Any, bs, metrics=None,
+def offload_slots(cache: Any, bs, telemetry, metrics=None,
                   tags: Optional[Dict[int, Dict[str, Any]]] = None
                   ) -> Dict[int, Dict[str, Any]]:
     """Host-offload SEVERAL slots at once (the periodic checkpoint path):
@@ -224,27 +224,34 @@ def offload_slots(cache: Any, bs, metrics=None,
     batch instead of once per slot.  Each returned blob is bit-identical
     to an :func:`offload_slot` call for the same slot (same keys, same
     ``__meta__`` record), so restore/validate treat them identically.
-    ``tags`` maps slot index -> that slot's tag dict."""
-    host = jax.device_get(cache)
+    ``tags`` maps slot index -> that slot's tag dict.  The two halves
+    run in the ``checkpoint.transfer`` and ``checkpoint.pack`` spans of
+    ``telemetry`` (a :class:`repro.serving.telemetry.Telemetry`), and
+    ``repro_checkpoint_transfer_bytes_total`` counts the bytes moved."""
+    with telemetry.span("checkpoint.transfer"):
+        host = jax.device_get(cache)
     leaves = jax.tree_util.tree_leaves_with_path(host)
     keyed = []
     for path, leaf in leaves:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
                        for p in path)
         keyed.append((key, np.asarray(leaf)))
+    _count_bytes(metrics, "repro_checkpoint_transfer_bytes_total",
+                 sum(arr.nbytes for _, arr in keyed))
     blobs: Dict[int, Dict[str, Any]] = {}
-    for b in bs:
-        out: Dict[str, np.ndarray] = {}
-        for key, arr in keyed:
-            if key == "pos":                     # [B]: batch on axis 0
-                out[key] = arr[b:b + 1].copy()
-            elif arr.ndim == 0:
-                out[key] = arr
-            else:                                # [n_rep, B, ...]
-                out[key] = arr[:, b:b + 1].copy()
-        blobs[b] = _finalize_blob(out, tags=(tags or {}).get(b))
-        _count_bytes(metrics, "repro_offload_bytes_total",
-                     _blob_nbytes(blobs[b]))
+    with telemetry.span("checkpoint.pack"):
+        for b in bs:
+            out: Dict[str, np.ndarray] = {}
+            for key, arr in keyed:
+                if key == "pos":                     # [B]: batch on axis 0
+                    out[key] = arr[b:b + 1].copy()
+                elif arr.ndim == 0:
+                    out[key] = arr
+                else:                                # [n_rep, B, ...]
+                    out[key] = arr[:, b:b + 1].copy()
+            blobs[b] = _finalize_blob(out, tags=(tags or {}).get(b))
+            _count_bytes(metrics, "repro_offload_bytes_total",
+                         _blob_nbytes(blobs[b]))
     return blobs
 
 

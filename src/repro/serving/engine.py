@@ -388,6 +388,7 @@ class ServingEngine:
         self._pending: List[Tuple[int, Request]] = []
         self._starved = 0
         self._no_progress = 0
+        self._req_s_t: Optional[float] = None   # last request-seconds count
         # fractional-interleave accumulator: policies may grant the
         # in-flight prefill group < 1.0 chunk per iteration next to
         # higher-priority decode slots; credit accrues until a chunk runs
@@ -436,8 +437,6 @@ class ServingEngine:
         self._m_queue = m.gauge(
             "repro_queue_depth", "requests waiting for a slot")
         self._m_live = m.gauge("repro_live_slots", "slots decoding now")
-        self._m_tps = m.gauge(
-            "repro_tokens_per_s", "steady-state token throughput per phase")
         self._m_submitted = m.counter(
             "repro_submitted_total", "requests submitted")
         self._m_admitted = m.counter(
@@ -456,6 +455,18 @@ class ServingEngine:
         self._m_ckpt_bytes = m.counter(
             "repro_checkpoint_bytes_total",
             "host bytes offloaded by checkpointing")
+        # counted by cache.offload_slots; registered here for its help
+        m.counter("repro_checkpoint_transfer_bytes_total",
+                  "device->host bytes a checkpoint moved (the whole cache); "
+                  "repro_checkpoint_bytes_total is the part it kept")
+        req_s = m.counter(
+            "repro_request_seconds_total",
+            "request-seconds spent queued (preempted ones included), in "
+            "prefill and in decode; decode over slots x window is the "
+            "slots' occupancy")
+        self._m_queued_s = req_s.labels(state="queued")
+        self._m_prefill_s = req_s.labels(state="prefill")
+        self._m_decode_s = req_s.labels(state="decode")
         self._m_climbs = m.counter(
             "repro_bucket_climbs_total",
             "decode dispatches entering a deeper KV rung (each pays "
@@ -488,6 +499,18 @@ class ServingEngine:
             "repro_recovery_ms",
             "wall time of one engine-restart rehydration pass (ms)")
 
+    def _count_request_seconds(self, now: float) -> None:
+        """Add the request-seconds by state since the last call: only
+        ``submit`` and ``step`` change the states, so the counts seen now
+        have held since then."""
+        if self._req_s_t is not None:
+            dt = max(0.0, now - self._req_s_t)
+            self._m_queued_s.inc(dt * len(self.queue))
+            self._m_prefill_s.inc(dt * self._open_pending())
+            self._m_decode_s.inc(
+                dt * sum(r is not None for r in self.live))
+        self._req_s_t = now
+
     def submit(self, req: Request) -> None:
         # validate here, before admission can pop the request and reserve
         # slots: a mid-group failure would strand co-batched requests.
@@ -512,6 +535,7 @@ class ServingEngine:
                 f"the vocab [0, {self.cfg.vocab_size}) — out-of-vocab ids "
                 "index garbage embedding rows")
         req.submit_t = self._clock()
+        self._count_request_seconds(req.submit_t)
         self.telemetry.begin_span(req.rid, prompt_len=len(req.prompt),
                                   max_new=req.max_new,
                                   deadline_ms=req.deadline_ms,
@@ -688,12 +712,12 @@ class ServingEngine:
     def _expired(self, req: Request, now: float) -> bool:
         return self.scheduler.expired(req, now)
 
-    def _expire_deadlines(self) -> None:
+    def _expire_deadlines(self, now: float) -> None:
         """Cancel queued / mid-prefill / mid-decode requests whose TTL has
-        run out (the scheduler owns the expiry decision; reclaiming slots
-        and group rows is mechanism and happens here), then fail queued
-        requests the policy's starvation bound has given up on."""
-        now = self._clock()
+        run out by ``now`` (the scheduler owns the expiry decision;
+        reclaiming slots and group rows is mechanism and happens here),
+        then fail queued requests the policy's starvation bound has given
+        up on."""
         for req in [r for r in self.queue if self._expired(r, now)]:
             self.queue.remove(req)
             self._fail(req, "timed_out", DeadlineExceeded(
@@ -837,9 +861,12 @@ class ServingEngine:
                 run_chunk = False
                 self._starved = 0    # group in flight: queue isn't starved
         if run_chunk:
-            t0 = self._clock()
-            emitted, done, diverged = ch.step()
-            dt_ms = (self._clock() - t0) * 1e3
+            with self.telemetry.span(
+                    "prefill.chunk",
+                    (r.rid for _, r in self._pending if not r.done),
+                    timed=True) as chunk:
+                emitted, done, diverged = ch.step()
+            dt_ms = (chunk.end - chunk.start) * 1e3
             info = ch.last_chunk
             self._chunk_ran = True
             self._progress = True
@@ -854,8 +881,6 @@ class ServingEngine:
                 self.telemetry.record_latency(
                     "prefill", info["bucket"], tok_ms,
                     compiled=info["fresh_compile"])
-                if not info["fresh_compile"] and tok_ms > 0:
-                    self._m_tps.labels(phase="prefill").set(1e3 / tok_ms)
                 self._m_tokens.labels(phase="prefill").inc(
                     info["valid_tokens"])
             self._m_prefill_ms.observe(dt_ms)
@@ -986,44 +1011,50 @@ class ServingEngine:
                 if r is not None and (due or r.ckpt_blob is None)]
         if not need:
             return
-        t0 = self._clock()
-        self.cache = dict(self.cache, pos=jnp.asarray(self.pos, jnp.int32))
-        # one full-cache transfer for the whole batch of due slots: the
-        # per-leaf dispatch overhead of slot-at-a-time offload dominated
-        # the healthy-path checkpoint cost
-        blobs = offload_slots(self.cache, [b for b, _ in need],
-                              metrics=self.metrics,
-                              tags={b: {"rid": r.rid, "priority": r.priority}
-                                    for b, r in need})
-        for b, req in need:
-            blob = blobs[b]
-            if self.faults.active:
-                blob = self.faults.corrupt_blob(req.rid, blob)
-            req.ckpt_blob = blob
-            req.ckpt_token = int(self.tokens[b, 0])
-            req.ckpt_pos = int(self.pos[b])
-            req.ckpt_out = len(req.out)
+        with self.telemetry.span("engine.checkpoint",
+                                 (r.rid for _, r in need),
+                                 timed=True) as span:
+            self.cache = dict(self.cache,
+                              pos=jnp.asarray(self.pos, jnp.int32))
+            # one full-cache transfer for the whole batch of due slots:
+            # the per-leaf dispatch overhead of slot-at-a-time offload
+            # dominated the healthy-path checkpoint cost
+            blobs = offload_slots(
+                self.cache, [b for b, _ in need], self.telemetry,
+                metrics=self.metrics,
+                tags={b: {"rid": r.rid, "priority": r.priority}
+                      for b, r in need})
+            for b, req in need:
+                blob = blobs[b]
+                if self.faults.active:
+                    blob = self.faults.corrupt_blob(req.rid, blob)
+                req.ckpt_blob = blob
+                req.ckpt_token = int(self.tokens[b, 0])
+                req.ckpt_pos = int(self.pos[b])
+                req.ckpt_out = len(req.out)
+                if self.store is not None:
+                    self.store.stage_blob(req.rid, blob)
+                    self._persist_request(req, state="live",
+                                          next_token=req.ckpt_token,
+                                          pos=req.ckpt_pos)
+                self.stats["checkpoints"] += 1
+                self._m_ckpts.inc()
+                self._m_ckpt_bytes.inc(sum(v.nbytes for v in blob.values()
+                                           if hasattr(v, "nbytes")))
+                self.telemetry.event(req.rid, "checkpoint")
             if self.store is not None:
-                self.store.stage_blob(req.rid, blob)
-                self._persist_request(req, state="live",
-                                      next_token=req.ckpt_token,
-                                      pos=req.ckpt_pos)
-            self.stats["checkpoints"] += 1
-            self._m_ckpts.inc()
-            self._m_ckpt_bytes.inc(sum(
-                v.nbytes for v in blob.values() if hasattr(v, "nbytes")))
-            self.telemetry.event(req.rid, "checkpoint")
-        if self.store is not None:
-            # crash point 1: blob files staged, manifest commit not yet
-            # landed — recovery must see the PREVIOUS manifest intact
-            if self.faults.active and self.faults.kill_now(it, point=1):
-                raise SimulatedCrash(
-                    "fault injection: kill between checkpoint stage and "
-                    f"manifest commit at iteration {it}")
-            self.store.commit()
+                # crash point 1: blob files staged, manifest commit not
+                # yet landed — recovery must see the PREVIOUS manifest
+                # intact
+                if self.faults.active and self.faults.kill_now(it,
+                                                               point=1):
+                    raise SimulatedCrash(
+                        "fault injection: kill between checkpoint stage "
+                        f"and manifest commit at iteration {it}")
+                self.store.commit()
         # observability for the < 5% healthy-path overhead budget: the
         # fault smoke gates on ckpt_ms / wall time
-        self.stats["ckpt_ms"] += (self._clock() - t0) * 1e3
+        self.stats["ckpt_ms"] += (span.end - span.start) * 1e3
 
     def _quarantine(self, b: int, req: Request) -> None:
         """Divergence sentinel tripped for slot ``b`` this burst: none of
@@ -1099,6 +1130,10 @@ class ServingEngine:
         slots.  Returns live + queued + in-prefill (terminal requests
         excluded).  Never raises for in-flight faults — failing requests
         land on :attr:`finished` with a structured status."""
+        with self.telemetry.span("engine.step"):
+            return self._step()
+
+    def _step(self) -> int:
         it = self.stats["iters"]
         # crash point 0: between iterations, before any state mutates —
         # everything committed through iteration it-1 must recover
@@ -1108,7 +1143,9 @@ class ServingEngine:
         self.stats["iters"] += 1
         self._chunk_ran = False
         self._progress = False
-        self._expire_deadlines()
+        now = self._clock()
+        self._count_request_seconds(now)
+        self._expire_deadlines(now)
         self._admit(it)
         chunk_ran = self._chunk_ran
         if not any(req is not None for req in self.live):
@@ -1141,21 +1178,23 @@ class ServingEngine:
             if self._max_bucket >= 0:
                 self._m_climbs.inc()
             self._max_bucket = kv_bucket
-        t0 = self._clock()
-        out = self._decode_n(self.params, self.cache,
-                             jnp.asarray(self.tokens), n=kblk,
-                             kv_bucket=kv_bucket, rope_len=self.rope_len,
-                             with_sentinel=self.sentinel)
-        if self.sentinel:
-            toks_d, self.cache, ok_d = out
-            # ONE host sync per block: tokens and sentinel flags fetched
-            # in a single batched transfer, not two round-trips
-            toks, okh = jax.device_get((toks_d, ok_d))
-        else:
-            toks_d, self.cache = out
-            toks = np.asarray(toks_d)
-            okh = None
-        dt_ms = (self._clock() - t0) * 1e3
+        with self.telemetry.span("decode.burst",
+                                 (r.rid for r in self.live if r is not None),
+                                 timed=True) as burst:
+            out = self._decode_n(self.params, self.cache,
+                                 jnp.asarray(self.tokens), n=kblk,
+                                 kv_bucket=kv_bucket, rope_len=self.rope_len,
+                                 with_sentinel=self.sentinel)
+            if self.sentinel:
+                toks_d, self.cache, ok_d = out
+                # ONE host sync per block: tokens and sentinel flags
+                # fetched in a single batched transfer, not two round-trips
+                toks, okh = jax.device_get((toks_d, ok_d))
+            else:
+                toks_d, self.cache = out
+                toks = np.asarray(toks_d)
+                okh = None
+        dt_ms = (burst.end - burst.start) * 1e3
         # per-token latency feeds the deadline admission controller and
         # preemption slack ordering, keyed by (phase, bucket); the first
         # dispatch per bucket is tagged a compile sample and segregated —
@@ -1165,8 +1204,6 @@ class ServingEngine:
                                       compiled=fresh_compile)
         self._m_decode_ms.observe(dt_ms)
         self.profiler.observe("decode", dt_ms)
-        if not fresh_compile and dt_ms > 0:
-            self._m_tps.labels(phase="decode").set(kblk * 1e3 / dt_ms)
         n_live = 0
         decoded = 0
         for b, req in enumerate(self.live):
@@ -1228,9 +1265,11 @@ class ServingEngine:
                         "outstanding"))
                     break
         finally:
-            # persist the measured latency model for the next process and
-            # flush metrics — both no-ops unless a path is configured
+            # persist the measured latency model for the next process,
+            # write out the step spans and flush metrics — each a no-op
+            # unless its path is configured
             self.telemetry.save_warmstart()
+            self.telemetry.write_step_spans()
             self.metrics.export()
             if self.store is not None:
                 self.store.commit()

@@ -12,7 +12,7 @@ unguarded sample poisons the steady-state estimate that deadline
 admission and preemption-victim selection depend on — one bucket-ladder
 climb could spuriously time out every queued request.
 
-This module replaces the scalars with three layers:
+This module replaces the scalars with four layers:
 
 * **Latency table** — :class:`TelemetryTable`, one
   :class:`PhaseBucketStats` per ``(arch, phase, kv_bucket)`` key
@@ -42,6 +42,14 @@ This module replaces the scalars with three layers:
   is set (or ``trace_path`` is passed), each finished span is appended
   to that file as one JSON line carrying ``version`` + ``arch``;
   :func:`read_trace` rejects lines written by an incompatible schema.
+* **Step spans** — :meth:`Telemetry.span` marks the engine's layer
+  boundaries (a step, its prefill chunk, decode burst and checkpoint,
+  and the checkpoint's transfer and pack).  Each one is a
+  ``jax.profiler.TraceAnnotation`` under its bare name, so a profiler
+  trace holds it on the device ops' clock; with a trace path set it is
+  also recorded on the engine clock (name, start, end, parent span,
+  request ids) in a buffer of the newest :data:`STEP_SPAN_BUFFER`, which
+  :meth:`Telemetry.write_step_spans` appends to the same JSONL file.
 * **Operator attribution** — :func:`operator_costs` maps a compiled XLA
   program to flop/byte totals (XLA's ``compiled.cost_analysis()``)
   plus per-kernel-family shares (gemm / ssm / norm / memory / arith /
@@ -56,11 +64,14 @@ base across deadlines, latency samples and trace spans).
 """
 from __future__ import annotations
 
+import collections
 import json
 import logging
 import os
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 log = logging.getLogger("repro.serving.telemetry")
 
@@ -69,8 +80,12 @@ PHASES = ("prefill", "decode")
 
 #: schema version for trace JSONL lines AND latency snapshots; bumped to 2
 #: when the table became arch-keyed (v1 lines have no arch and would be
-#: misattributed — read_trace rejects them)
-TRACE_SCHEMA_VERSION = 2
+#: misattributed — read_trace rejects them), to 3 when step spans joined
+#: request spans in the file and every line gained a ``type``
+TRACE_SCHEMA_VERSION = 3
+
+#: step spans kept in memory while a trace path is set (the newest win)
+STEP_SPAN_BUFFER = 65536
 
 #: schema version of the warm-start blob (arch-keyed table serialization)
 TELEMETRY_BLOB_VERSION = 1
@@ -237,14 +252,52 @@ class TelemetryTable:
         return loaded
 
 
+class _Span(TraceAnnotation):
+    """A profiler annotation under its bare name that also reads its
+    telemetry's clock at both ends (``start``, ``end``)."""
+
+    __slots__ = ("_tel", "start", "end")
+
+    def __enter__(self) -> "_Span":
+        TraceAnnotation.__enter__(self)
+        self.start = self._tel._clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end = self._tel._clock()
+        TraceAnnotation.__exit__(self, *exc)
+
+
+class _RecordedSpan(_Span):
+    """A :class:`_Span` that, on exit, appends ``(name, start, end,
+    parent, rids)`` to its telemetry's step spans, ``parent`` being the
+    innermost recorded span open around it."""
+
+    __slots__ = ("name", "rids", "parent")
+
+    def __enter__(self) -> "_RecordedSpan":
+        opened = self._tel._open
+        self.parent = opened[-1] if opened else None
+        opened.append(self.name)
+        return _Span.__enter__(self)
+
+    def __exit__(self, *exc) -> None:
+        _Span.__exit__(self, *exc)
+        tel = self._tel
+        tel._open.pop()
+        tel.step_spans.append((self.name, self.start, self.end,
+                               self.parent, self.rids))
+
+
 class Telemetry:
     """Metrics + tracing front for one :class:`ServingEngine` (or bench),
     bound to one ``arch`` over a (possibly shared) :class:`TelemetryTable`.
 
     ``clock`` is the time base (seconds); ``alpha`` the EWMA smoothing
-    factor shared by every record; ``trace_path`` enables JSONL span
-    export (defaults to the ``REPRO_TRACE_PATH`` env var, read once at
-    construction); ``warmstart_path`` (default: the
+    factor shared by every record; ``trace_path`` enables JSONL export
+    of request spans and the recording of step spans (defaults to the
+    ``REPRO_TRACE_PATH`` env var, read once at construction);
+    ``warmstart_path`` (default: the
     ``REPRO_TELEMETRY_WARMSTART`` env var) names a blob to load at
     construction — if it exists — and to save via
     :meth:`save_warmstart`.  A bad blob logs a warning and leaves the
@@ -280,6 +333,50 @@ class Telemetry:
                          n, self.warmstart_path)
         self._spans: Dict[int, Dict[str, Any]] = {}    # rid -> open span
         self.finished_spans: List[Dict[str, Any]] = []
+        # step spans (name, start, end, parent, rids), kept only while a
+        # trace path is set; _open names the spans entered, innermost last
+        self.step_spans: collections.deque = collections.deque(
+            maxlen=STEP_SPAN_BUFFER)
+        self._open: List[str] = []
+
+    # --------------------------------------------------------- step spans
+    def span(self, name: str, rids: Iterable[int] = (), *,
+             timed: bool = False):
+        """Context manager marking one layer boundary of the engine.
+
+        It always enters ``jax.profiler.TraceAnnotation(name)`` with the
+        bare name, so a profiler trace holds the span on the same clock as
+        the device's operations.  With a trace path set it also records
+        ``(name, start, end, parent, rids)`` on this telemetry's clock,
+        ``parent`` being the innermost span open around it.  ``timed``
+        reads the clock at both ends even without a trace path, for a
+        caller that times the interval itself (``.start``/``.end`` of the
+        returned span); otherwise an unrecorded span reads no clock."""
+        if self.trace_path:
+            span = _RecordedSpan(name)
+            span.name, span.rids = name, [int(r) for r in rids]
+        elif timed:
+            span = _Span(name)
+        else:
+            return TraceAnnotation(name)
+        span._tel = self
+        return span
+
+    def write_step_spans(self) -> int:
+        """Append the buffered step spans to the trace path as
+        ``type: "step"`` lines and empty the buffer; returns how many
+        were written (0 without a trace path)."""
+        if not self.trace_path or not self.step_spans:
+            return 0
+        n = len(self.step_spans)
+        with open(self.trace_path, "a") as f:
+            for name, start, end, parent, rids in self.step_spans:
+                f.write(json.dumps({
+                    "version": TRACE_SCHEMA_VERSION, "type": "step",
+                    "arch": self.arch, "name": name, "start": start,
+                    "end": end, "parent": parent, "rids": rids}) + "\n")
+        self.step_spans.clear()
+        return n
 
     # ------------------------------------------------------- latency table
     def record_latency(self, phase: str, bucket: Optional[int],
@@ -300,7 +397,7 @@ class Telemetry:
 
     def latency_snapshot(self) -> Dict[str, Any]:
         """JSON-able view of this arch's slice of the table:
-        ``{"version": 2, "arch": ..., "table": {"decode@256": {...},
+        ``{"version": 3, "arch": ..., "table": {"decode@256": {...},
         ...}}`` (``@*`` = phase-global aggregate, ``@-1`` =
         unbucketed)."""
         return {"version": TRACE_SCHEMA_VERSION, "arch": self.arch,
@@ -325,8 +422,9 @@ class Telemetry:
         crossed a process boundary (its ``submit_t`` is back-dated to
         preserve the deadline budget already consumed)."""
         self._spans[rid] = {
-            "version": TRACE_SCHEMA_VERSION, "arch": self.arch,
-            "rid": rid, "submit_t": self._clock() if t is None else t,
+            "version": TRACE_SCHEMA_VERSION, "type": "request",
+            "arch": self.arch, "rid": rid,
+            "submit_t": self._clock() if t is None else t,
             "prompt_len": int(prompt_len), "max_new": int(max_new),
             "deadline_ms": deadline_ms, "priority": int(priority),
             "status": "pending", "events": [], **fields}
@@ -448,11 +546,12 @@ def operator_costs(compiled) -> Dict[str, Any]:
     return out
 
 
-def read_trace(path: str) -> List[Dict[str, Any]]:
-    """Load a JSONL span trace written via ``REPRO_TRACE_PATH`` (one span
-    object per line; blank lines ignored).  Raises ``ValueError`` when a
-    line carries a different schema ``version`` — stale traces from an
-    earlier (or later) layout must not be silently misread."""
+def read_trace(path: str, type: str = "request") -> List[Dict[str, Any]]:
+    """Load the lines of one ``type`` from a JSONL trace written via
+    ``REPRO_TRACE_PATH``: ``"request"`` spans (the default) or ``"step"``
+    spans.  Blank lines are ignored.  Raises ``ValueError`` when a line
+    carries a different schema ``version`` — stale traces from an earlier
+    (or later) layout must not be silently misread."""
     spans = []
     with open(path) as f:
         for i, line in enumerate(f):
@@ -465,5 +564,6 @@ def read_trace(path: str) -> List[Dict[str, Any]]:
                 raise ValueError(
                     f"{path}:{i + 1}: trace span has schema version {v!r}, "
                     f"expected {TRACE_SCHEMA_VERSION} — stale trace file?")
-            spans.append(span)
+            if span.get("type") == type:
+                spans.append(span)
     return spans
