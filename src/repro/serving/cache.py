@@ -100,9 +100,7 @@ def slot_schema(cache: Any) -> Dict[str, Any]:
     config so an engine never rehydrates blobs shaped for a different
     cache layout."""
     out: Dict[str, Any] = {}
-    for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
-        key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
-                       for p in path)
+    for key, leaf in _keyed_leaves(cache):
         if key == "pos":                         # [B]: batch on axis 0
             shape: Tuple[int, ...] = (1,)
         elif leaf.ndim == 0:
@@ -195,6 +193,13 @@ def _count_bytes(metrics, name: str, nbytes: int) -> None:
         metrics.counter(name, "host<->device cache traffic").inc(nbytes)
 
 
+def _keyed_leaves(tree: Any):
+    """(blob key, leaf) pairs of a cache pytree, keys as ``a/b/c``."""
+    return [("/".join(str(getattr(p, "key", getattr(p, "idx", p)))
+                      for p in path), leaf)
+            for path, leaf in jax.tree_util.tree_leaves_with_path(tree)]
+
+
 def offload_slot(cache: Any, b: int, metrics=None,
                  tags: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
     """Host-offload one slot (preempted request / periodic checkpoint) as
@@ -205,54 +210,59 @@ def offload_slot(cache: Any, b: int, metrics=None,
     class after the engine that wrote it is gone — and so restore can
     refuse a blob that was offloaded for a different request."""
     one = jax.device_get(extract_slot(cache, b))   # one batched transfer
-    out: Dict[str, Any] = {}
-    for path, leaf in jax.tree_util.tree_leaves_with_path(one):
-        key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
-                       for p in path)
-        out[key] = np.asarray(leaf)
+    out = {key: np.asarray(leaf) for key, leaf in _keyed_leaves(one)}
     blob = _finalize_blob(out, tags=tags)
     _count_bytes(metrics, "repro_offload_bytes_total", _blob_nbytes(blob))
     return blob
 
 
+def start_offload(cache: Any, bs, metrics=None
+                  ) -> Dict[int, list]:
+    """First half of a checkpoint of slots ``bs``: gather each due slot's
+    rows of every leaf (and its ``pos`` entry) into fresh device buffers,
+    one :func:`extract_slot` dispatch a slot, and start their copies to
+    the host.  Only the due slots' bytes move; each slot's leaf is its
+    own buffer, so it arrives contiguous, laid out as its blob keeps it.
+    The buffers are new, so the caller may donate ``cache`` at once.
+    Returns slot -> ``(key, device array)`` pairs for
+    :func:`finish_offload`; ``repro_checkpoint_transfer_bytes_total``
+    and ``repro_offload_bytes_total`` count the bytes moved."""
+    parts: Dict[int, list] = {}
+    for b in bs:
+        parts[b] = _keyed_leaves(extract_slot(cache, b))
+        for _, leaf in parts[b]:
+            leaf.copy_to_host_async()
+    nbytes = sum(leaf.nbytes for p in parts.values() for _, leaf in p)
+    _count_bytes(metrics, "repro_checkpoint_transfer_bytes_total", nbytes)
+    _count_bytes(metrics, "repro_offload_bytes_total", nbytes)
+    return parts
+
+
+def finish_offload(part: list, telemetry,
+                   tags: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
+    """Second half, for one slot of :func:`start_offload`: wait for its
+    host copy (span ``checkpoint.transfer``), then build its blob (span
+    ``checkpoint.pack``), bit-identical to :func:`offload_slot`'s for the
+    same slot.  Safe on a worker thread: ``zlib.crc32`` releases the
+    GIL."""
+    with telemetry.span("checkpoint.transfer"):
+        out = {key: np.asarray(leaf) for key, leaf in part}
+    with telemetry.span("checkpoint.pack"):
+        return _finalize_blob(out, tags=tags)
+
+
 def offload_slots(cache: Any, bs, telemetry, metrics=None,
                   tags: Optional[Dict[int, Dict[str, Any]]] = None
                   ) -> Dict[int, Dict[str, Any]]:
-    """Host-offload SEVERAL slots at once (the periodic checkpoint path):
-    one ``device_get`` of the whole cache, then per-slot numpy slicing on
-    the host — per-leaf dispatch/transfer overhead is paid once for the
-    batch instead of once per slot.  Each returned blob is bit-identical
-    to an :func:`offload_slot` call for the same slot (same keys, same
-    ``__meta__`` record), so restore/validate treat them identically.
-    ``tags`` maps slot index -> that slot's tag dict.  The two halves
-    run in the ``checkpoint.transfer`` and ``checkpoint.pack`` spans of
-    ``telemetry`` (a :class:`repro.serving.telemetry.Telemetry`), and
-    ``repro_checkpoint_transfer_bytes_total`` counts the bytes moved."""
-    with telemetry.span("checkpoint.transfer"):
-        host = jax.device_get(cache)
-    leaves = jax.tree_util.tree_leaves_with_path(host)
-    keyed = []
-    for path, leaf in leaves:
-        key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
-                       for p in path)
-        keyed.append((key, np.asarray(leaf)))
-    _count_bytes(metrics, "repro_checkpoint_transfer_bytes_total",
-                 sum(arr.nbytes for _, arr in keyed))
-    blobs: Dict[int, Dict[str, Any]] = {}
-    with telemetry.span("checkpoint.pack"):
-        for b in bs:
-            out: Dict[str, np.ndarray] = {}
-            for key, arr in keyed:
-                if key == "pos":                     # [B]: batch on axis 0
-                    out[key] = arr[b:b + 1].copy()
-                elif arr.ndim == 0:
-                    out[key] = arr
-                else:                                # [n_rep, B, ...]
-                    out[key] = arr[:, b:b + 1].copy()
-            blobs[b] = _finalize_blob(out, tags=(tags or {}).get(b))
-            _count_bytes(metrics, "repro_offload_bytes_total",
-                         _blob_nbytes(blobs[b]))
-    return blobs
+    """Host-offload SEVERAL slots at once: :func:`start_offload`, then
+    :func:`finish_offload` of each slot in turn on the calling thread.
+    Each returned blob is bit-identical to an :func:`offload_slot` call
+    for the same slot (same keys, same ``__meta__`` record), so
+    restore/validate treat them identically.  ``tags`` maps slot index ->
+    that slot's tag dict."""
+    parts = start_offload(cache, bs, metrics=metrics)
+    return {b: finish_offload(parts[b], telemetry, (tags or {}).get(b))
+            for b in bs}
 
 
 def validate_blob(blob: Dict[str, Any], template_keys,
@@ -320,9 +330,8 @@ def restore_slot(cache: Any, blob: Dict[str, Any], b: int,
                     f"blob identity tag {k!r} mismatch: blob carries "
                     f"{tags[k]!r}, restore expects {v!r}", rid=rid)
     one = extract_slot(cache, b)   # template structure
-    leaves = jax.tree_util.tree_leaves_with_path(one)
-    keys = ["/".join(str(getattr(p, "key", getattr(p, "idx", p)))
-                     for p in path) for path, _ in leaves]
+    leaves = _keyed_leaves(one)
+    keys = [k for k, _ in leaves]
     data = validate_blob(blob, keys, rid=rid)
     for k, (_, tmpl) in zip(keys, leaves):
         if tuple(data[k].shape) != tuple(tmpl.shape):

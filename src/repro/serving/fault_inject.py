@@ -199,13 +199,24 @@ class FaultPlan:
                      blob: Dict[str, Any]) -> Dict[str, Any]:
         """Bit-flip one payload byte of ``blob`` if a clause targets
         ``rid``; returns the (possibly copied+damaged) blob."""
+        return self.damage_blob(rid, blob) if self.blob_hit(rid) else blob
+
+    def blob_hit(self, rid: int) -> bool:
+        """Spend the ``corrupt_blob`` clauses targeting ``rid``: True when
+        ``rid``'s blob taken now is to be damaged.  The engine asks when
+        it takes a checkpoint and damages the blob when it is finished,
+        so clauses are spent in the order the checkpoints were taken."""
         hit = False
         for c in self.clauses:
             if (c.kind == "corrupt_blob" and c.params["rid"] == rid
                     and c._spend()):
                 hit = True
-        if not hit:
-            return blob
+        return hit
+
+    def damage_blob(self, rid: int,
+                    blob: Dict[str, Any]) -> Dict[str, Any]:
+        """The copy of ``blob`` with one payload bit flipped, chosen from
+        the plan's seed and ``rid``."""
         keys = sorted(k for k, v in blob.items()
                       if isinstance(v, np.ndarray) and v.nbytes > 0)
         if not keys:

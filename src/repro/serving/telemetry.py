@@ -47,8 +47,8 @@ This module replaces the scalars with four layers:
   and the checkpoint's transfer and pack).  Each one is a
   ``jax.profiler.TraceAnnotation`` under its bare name, so a profiler
   trace holds it on the device ops' clock; with a trace path set it is
-  also recorded on the engine clock (name, start, end, parent span,
-  request ids) in a buffer of the newest :data:`STEP_SPAN_BUFFER`, which
+  also recorded on the engine clock (name, start, end, parent span on
+  the same thread, request ids) in a buffer of the newest :data:`STEP_SPAN_BUFFER`, which
   :meth:`Telemetry.write_step_spans` appends to the same JSONL file.
 * **Operator attribution** — :func:`operator_costs` maps a compiled XLA
   program to flop/byte totals (XLA's ``compiled.cost_analysis()``)
@@ -68,6 +68,7 @@ import collections
 import json
 import logging
 import os
+import threading
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
@@ -334,10 +335,22 @@ class Telemetry:
         self._spans: Dict[int, Dict[str, Any]] = {}    # rid -> open span
         self.finished_spans: List[Dict[str, Any]] = []
         # step spans (name, start, end, parent, rids), kept only while a
-        # trace path is set; _open names the spans entered, innermost last
+        # trace path is set; _open names the spans entered on the calling
+        # thread, innermost last
         self.step_spans: collections.deque = collections.deque(
             maxlen=STEP_SPAN_BUFFER)
-        self._open: List[str] = []
+        self._threads = threading.local()
+
+    @property
+    def _open(self) -> List[str]:
+        """The recorded spans open on the calling thread: a span entered
+        on a worker thread (a checkpoint's transfer and pack) takes its
+        parent there, never from the step loop's stack."""
+        try:
+            return self._threads.open
+        except AttributeError:
+            self._threads.open = []
+            return self._threads.open
 
     # --------------------------------------------------------- step spans
     def span(self, name: str, rids: Iterable[int] = (), *,
@@ -370,12 +383,12 @@ class Telemetry:
             return 0
         n = len(self.step_spans)
         with open(self.trace_path, "a") as f:
-            for name, start, end, parent, rids in self.step_spans:
+            for _ in range(n):   # popleft: worker threads may append
+                name, start, end, parent, rids = self.step_spans.popleft()
                 f.write(json.dumps({
                     "version": TRACE_SCHEMA_VERSION, "type": "step",
                     "arch": self.arch, "name": name, "start": start,
                     "end": end, "parent": parent, "rids": rids}) + "\n")
-        self.step_spans.clear()
         return n
 
     # ------------------------------------------------------- latency table
