@@ -20,6 +20,7 @@ from repro.configs.gemma3_1b import CONFIG as gemma3_1b              # noqa: F40
 from repro.configs.smollm_135m import CONFIG as smollm_135m          # noqa: F401,E402
 from repro.configs.mamba2_2p7b import CONFIG as mamba2_2p7b          # noqa: F401,E402
 from repro.configs.llava_next_mistral_7b import CONFIG as llava_next # noqa: F401,E402
+from repro.configs.falcon_h1_34b import CONFIG as falcon_h1_34b      # noqa: F401,E402
 from repro.configs import paper_models                               # noqa: F401,E402
 
 ASSIGNED = (
@@ -49,7 +50,13 @@ def reduced(cfg: ModelConfig, *, d_model: int = 64, vocab: int = 256,
 
     ssm = cfg.ssm
     if ssm is not None:
-        ssm = dataclasses.replace(ssm, d_state=16, headdim=16, chunk=16)
+        # an explicit SSM width keeps its ratio to d_model, in whole
+        # 16-wide heads per group
+        unit = 16 * ssm.n_groups
+        d_ssm = (None if ssm.d_ssm is None else max(
+            unit, ssm.d_ssm * d_model // cfg.d_model // unit * unit))
+        ssm = dataclasses.replace(ssm, d_state=16, headdim=16, chunk=16,
+                                  d_ssm=d_ssm)
     moe = cfg.moe
     if moe is not None:
         moe = dataclasses.replace(moe, n_experts=8,

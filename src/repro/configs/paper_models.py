@@ -1,9 +1,8 @@
 """The paper's own model suite (Table II) — used by the figure benchmarks.
 
-Public configs; the Falcon-H1 parallel hybrid-head layout is approximated
-with interleaved mamba2/attention layers (our block system is sequential;
-noted in DESIGN.md §Arch-applicability).  Hymba (head-parallel hybrid) is
-not reproduced for the same reason.
+Public configs.  Falcon-H1-0.5B and the Hymba proxy run the parallel
+``hybrid_par`` layer without Falcon-H1's muP multipliers or its decoupled
+SSM width, so both stay approximations of their published models.
 """
 from repro.core.config import AttnConfig, ModelConfig, SSMConfig
 from repro.core.registry import register
@@ -60,8 +59,10 @@ MAMBA2_780M = register(ModelConfig(
     layer_pattern=("mamba2",), tie_embeddings=True,
 ), tags=("paper", "ssm"))
 
-# Falcon-H1-0.5B: parallel hybrid heads (attention + Mamba-2 side by side
-# in every layer — the real Falcon-H1 topology via the hybrid_par block).
+# Falcon-H1-0.5B, approximated: hybrid_par lays attention and Mamba-2 side
+# by side in every layer as Falcon-H1 does, but this entry leaves its muP
+# multipliers at 1 (``mup`` None) and its SSM width at expand x d_model, so
+# it is not the published model (falcon-h1-34b is, at its own widths).
 FALCON_H1_05B = register(ModelConfig(
     name="falcon-h1-0.5b", family="hybrid", n_layers=18, d_model=1024,
     d_ff=4096, vocab_size=32784,

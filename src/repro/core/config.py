@@ -49,12 +49,37 @@ class SSMConfig:
     conv_kernel: int = 4
     chunk: int = 128          # SSD chunk length (MXU-aligned)
     dt_rank: Optional[int] = None  # mamba1: rank of the dt projection
+    # explicit SSM inner width (Falcon-H1's ``mamba_d_ssm``); None keeps
+    # the Mamba-2 default ``expand * d_model``
+    d_ssm: Optional[int] = None
 
     def d_inner(self, d_model: int) -> int:
-        return self.expand * d_model
+        return self.d_ssm if self.d_ssm is not None else self.expand * d_model
 
     def n_ssm_heads(self, d_model: int) -> int:
         return self.d_inner(d_model) // self.headdim
+
+
+@dataclass(frozen=True)
+class MupConfig:
+    """Falcon-H1's muP multipliers: fixed scalars applied to activations
+    where the published model applies them.  ``ssm`` scales the Mamba
+    in-projection's z | x | B | C | dt slices; ``mlp`` the gate projection
+    (before its activation) and the down projection."""
+    embedding: float = 1.0
+    lm_head: float = 1.0
+    attn_in: float = 1.0
+    attn_out: float = 1.0
+    key: float = 1.0
+    ssm_in: float = 1.0
+    ssm_out: float = 1.0
+    ssm: Tuple[float, float, float, float, float] = (1.0,) * 5
+    mlp: Tuple[float, float] = (1.0, 1.0)
+
+    def __post_init__(self):
+        # lists from a JSON file become tuples: configs key jit caches
+        object.__setattr__(self, "ssm", tuple(self.ssm))
+        object.__setattr__(self, "mlp", tuple(self.mlp))
 
 
 @dataclass(frozen=True)
@@ -103,6 +128,8 @@ class ModelConfig:
     # Zamba2: one shared transformer block applied at "mamba2+shared" positions.
     shared_attn: Optional[AttnConfig] = None
     shared_attn_d_ff: int = 0
+    # muP multipliers (Falcon-H1); None emits no multiply at all
+    mup: Optional[MupConfig] = None
     scan_layers: bool = True     # scan-over-layers (compact HLO); False unrolls
     remat: str = "block"         # "none" | "block" (remat each scanned unit)
     # FSDP: shard the d_model dim of *params* over the data axis (ZeRO-3).

@@ -34,7 +34,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
                                       else q_offset,
                                       kv_wrap=kv_wrap, ring_len=ring_len)
         from repro.kernels.flash.kernel import flash_attention_pallas
-        return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      q_offset=q_offset,
-                                      kv_wrap=kv_wrap, ring_len=ring_len,
-                                      interpret=(backend == "interpret"))
+        # the kernel's call takes this scope's name in the compiled program
+        # (and in a device trace), with the KV rows it was given, since
+        # its result shape does not show them
+        with jax.named_scope(f"flash_prefill_kv{k.shape[2]}"):
+            return flash_attention_pallas(
+                q, k, v, causal=causal, window=window, q_offset=q_offset,
+                kv_wrap=kv_wrap, ring_len=ring_len,
+                interpret=(backend == "interpret"))

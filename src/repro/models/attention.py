@@ -26,7 +26,7 @@ from repro.distributed.sharding import constrain
 from repro.kernels import dispatch as kdispatch
 from repro.kernels.flash.ref import ring_kv_positions
 from repro.models.params import ParamDef
-from repro.models.norms import head_rms_norm
+from repro.models.norms import head_rms_norm, scale_by
 from repro.models.rope import apply_rope
 
 NEG_INF = -1e30
@@ -230,7 +230,9 @@ def attention(p: Dict, x: jax.Array, a: AttnConfig, *,
               pos: Optional[jax.Array] = None,
               kv_repeat: int = 1,
               chunk_mask: Optional[jax.Array] = None,
-              eps: float = 1e-6) -> Tuple[jax.Array, Optional[Dict]]:
+              eps: float = 1e-6,
+              key_mult: Optional[float] = None
+              ) -> Tuple[jax.Array, Optional[Dict]]:
     """Full attention sub-block: qkv proj -> rope -> core -> out proj.
 
     cache=None: full-sequence (train/prefill, no cache returned).
@@ -241,12 +243,17 @@ def attention(p: Dict, x: jax.Array, a: AttnConfig, *,
     prefix of the chunk per row; rolling (ring-buffer) caches use it to
     gate their writes — an invalid token must never overwrite live ring
     history (append-only caches just let later writes/masks hide it).
+
+    ``key_mult`` (Falcon-H1's muP ``key_multiplier``, or None) scales the
+    projected keys before rope, so the cache holds the scaled keys.
     """
     b, s, _ = x.shape
     with jax.named_scope("qkv_proj"):
         q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
         k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(x.dtype))
         v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(x.dtype))
+        if key_mult is not None:
+            k = scale_by(k, key_mult)
     if a.qk_norm:
         q = head_rms_norm(q, p["q_norm"], eps)
         k = head_rms_norm(k, p["k_norm"], eps)

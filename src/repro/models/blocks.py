@@ -12,7 +12,7 @@ from repro.models import mamba2 as m2
 from repro.models.attention import attention, attn_param_defs, init_attn_cache
 from repro.models.mlp import mlp, mlp_param_defs
 from repro.models.moe import moe, moe_param_defs
-from repro.models.norms import rms_norm
+from repro.models.norms import rms_norm, scale_by
 from repro.models.params import ParamDef
 
 ATTN_KINDS = ("dense", "local", "encoder", "moe", "dense_moe")
@@ -133,28 +133,38 @@ def apply_layer(cfg: ModelConfig, kind: str, p: Dict, x: jax.Array, *,
         return _residual(x), new_cache
 
     if kind == "hybrid_par":
-        # Falcon-H1-style parallel hybrid heads: attention + SSM branches
-        # read the same normed input; outputs sum into the residual.
+        # Falcon-H1's parallel hybrid layer: one RMSNorm feeds attention
+        # and a Mamba-2 mixer side by side, their outputs sum into the
+        # residual, then a second RMSNorm feeds the MLP.  ``cfg.mup``
+        # carries the published muP multipliers (none when it is None).
+        mup = cfg.mup
         h = rms_norm(x, p["ln1"], eps)
-        attn_cache = ({"k": cache["k"], "v": cache["v"]}
-                      if cache is not None else None)
-        a_out, new_attn = attention(p["attn"], h, cfg.attn, rope=rope,
-                                    cache=attn_cache, pos=pos,
-                                    kv_repeat=kv_repeat,
-                                    chunk_mask=chunk_mask, eps=eps)
         mcache = ({"conv": cache["conv"], "ssm": cache["ssm"]}
                   if cache is not None else None)
+        h_ssm = h if mup is None else scale_by(h, mup.ssm_in)
+        zxbcdt = None if mup is None else mup.ssm
         is_decode = cache is not None and x.shape[1] == 1 and pos is not None
         if is_decode:
-            m_out, new_m = m2.mamba2_decode(p["mamba"], h, cfg.ssm,
-                                            cfg.d_model, cache=mcache, eps=eps)
+            m_out, new_m = m2.mamba2_decode(p["mamba"], h_ssm, cfg.ssm,
+                                            cfg.d_model, cache=mcache,
+                                            eps=eps, zxbcdt=zxbcdt)
         else:
-            m_out, new_m = m2.mamba2_block(p["mamba"], h, cfg.ssm,
+            m_out, new_m = m2.mamba2_block(p["mamba"], h_ssm, cfg.ssm,
                                            cfg.d_model, cache=mcache, eps=eps,
-                                           mask=chunk_mask)
-        x = x + a_out + m_out
+                                           mask=chunk_mask, zxbcdt=zxbcdt)
+        attn_cache = ({"k": cache["k"], "v": cache["v"]}
+                      if cache is not None else None)
+        a_out, new_attn = attention(
+            p["attn"], h if mup is None else scale_by(h, mup.attn_in),
+            cfg.attn, rope=rope, cache=attn_cache, pos=pos,
+            kv_repeat=kv_repeat, chunk_mask=chunk_mask, eps=eps,
+            key_mult=None if mup is None else mup.key)
+        if mup is not None:
+            m_out = scale_by(m_out, mup.ssm_out)
+            a_out = scale_by(a_out, mup.attn_out)
+        x = x + (m_out + a_out)
         h = rms_norm(x, p["ln2"], eps)
-        x = x + mlp(p["mlp"], h, cfg.act)
+        x = x + mlp(p["mlp"], h, cfg.act, None if mup is None else mup.mlp)
         new_cache = None
         if cache is not None:
             new_cache = dict(new_m or {})

@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from repro.core.config import ModelConfig
 from repro.distributed.sharding import constrain
 from repro.models import blocks
-from repro.models.norms import rms_norm
+from repro.models.norms import rms_norm, scale_by
 from repro.models.params import ParamDef, init_params, param_axes, stack_defs
 from repro.models.rope import rope_tables
 
@@ -121,6 +121,8 @@ def _embed(cfg: ModelConfig, params, inputs: Dict[str, jax.Array]) -> jax.Array:
             emb = params["embed"]
             x = jnp.take(emb, inputs["tokens"], axis=0)
             x = x.astype(jnp.dtype(cfg.compute_dtype))
+            if cfg.mup is not None:
+                x = scale_by(x, cfg.mup.embedding)
             if cfg.frontend == "vision" and "features" in inputs:
                 feats = jnp.einsum(
                     "bnf,fd->bnd",
@@ -139,6 +141,8 @@ def _head(cfg: ModelConfig, params, x: jax.Array) -> jax.Array:
         else:
             logits = jnp.einsum("bsd,dv->bsv", x,
                                 params["lm_head"].astype(x.dtype))
+        if cfg.mup is not None:
+            logits = scale_by(logits, cfg.mup.lm_head)
         if cfg.padded_vocab != cfg.vocab_size:
             mask = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
             logits = jnp.where(mask[None, None, :], logits, -1e30)

@@ -1,17 +1,18 @@
 """Mamba-2 (SSD) block — in/out projections + conv1d + chunked SSD core."""
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.config import SSMConfig
 from repro.distributed.sharding import constrain
 from repro.kernels.conv1d.ops import causal_conv1d
 from repro.kernels.decode_fused.ops import mamba2_decode_fused
 from repro.kernels.ssd.ops import ssd_chunked_raw
-from repro.models.norms import gated_rms_norm
+from repro.models.norms import gated_rms_norm, scale_by
 from repro.models.params import ParamDef
 
 
@@ -65,9 +66,22 @@ def _split_xbc(xbc: jax.Array, s: SSMConfig, d_model: int):
             cm.reshape(*lead, s.n_groups, s.d_state))
 
 
+def _scale_in_proj(z, xbc, dt_raw, zxbcdt: Sequence[float], s: SSMConfig,
+                   d_model: int):
+    """Falcon-H1's muP vector over the in-projection: one multiplier each
+    for the z, x, B, C and dt slices."""
+    mz, mx, mb, mc, mdt = zxbcdt
+    gn = s.n_groups * s.d_state
+    vec = np.concatenate([np.full(s.d_inner(d_model), mx, np.float32),
+                          np.full(gn, mb, np.float32),
+                          np.full(gn, mc, np.float32)])
+    return scale_by(z, mz), scale_by(xbc, vec), scale_by(dt_raw, mdt)
+
+
 def mamba2_block(p: Dict, x: jax.Array, s: SSMConfig, d_model: int, *,
                  cache: Optional[Dict] = None, eps: float = 1e-5,
-                 mask: Optional[jax.Array] = None
+                 mask: Optional[jax.Array] = None,
+                 zxbcdt: Optional[Sequence[float]] = None
                  ) -> Tuple[jax.Array, Optional[Dict]]:
     """Full-sequence pass. If cache is given (prefill), returns final states.
 
@@ -75,7 +89,10 @@ def mamba2_block(p: Dict, x: jax.Array, s: SSMConfig, d_model: int, *,
     left-aligned prefix.  Invalid tokens are inert — their dt is driven to
     zero (state passes through unchanged) and the conv state is rebuilt
     from the trailing *valid* inputs, so the returned states equal those of
-    a prefill over only the valid prefix."""
+    a prefill over only the valid prefix.
+
+    ``zxbcdt`` (five floats, or None for none): muP multipliers of the
+    in-projection's z, x, B, C and dt slices (Falcon-H1)."""
     b, seq, _ = x.shape
     di = s.d_inner(d_model)
     nh = s.n_ssm_heads(d_model)
@@ -84,6 +101,9 @@ def mamba2_block(p: Dict, x: jax.Array, s: SSMConfig, d_model: int, *,
         z = jnp.einsum("bsd,de->bse", x, p["wz"].astype(dt_))
         xbc = jnp.einsum("bsd,de->bse", x, p["wxBC"].astype(dt_))
         dt_raw = jnp.einsum("bsd,dh->bsh", x, p["wdt"].astype(dt_))
+        if zxbcdt is not None:
+            z, xbc, dt_raw = _scale_in_proj(z, xbc, dt_raw, zxbcdt, s,
+                                            d_model)
     xbc = constrain(xbc, ("batch", "seq", "conv_dim"))
     if mask is not None:
         # -30 ⇒ softplus -> 0 ⇒ invalid tokens update no SSM state
@@ -113,7 +133,7 @@ def mamba2_block(p: Dict, x: jax.Array, s: SSMConfig, d_model: int, *,
                                    initial_state=init_ssm)
     y = y[:, :seq].reshape(b, seq, di)
     y = constrain(y, ("batch", "seq", "conv_dim"))
-    y = gated_rms_norm(y, z, p["norm_scale"], eps)
+    y = gated_rms_norm(y, z, p["norm_scale"], eps, groups=s.n_groups)
     with jax.named_scope("ssm_out_proj"):
         out = jnp.einsum("bse,ed->bsd", y, p["out_proj"].astype(dt_))
     out = constrain(out, ("batch", "seq", "embed"))
@@ -125,9 +145,12 @@ def mamba2_block(p: Dict, x: jax.Array, s: SSMConfig, d_model: int, *,
 
 
 def mamba2_decode(p: Dict, x: jax.Array, s: SSMConfig, d_model: int, *,
-                  cache: Dict, eps: float = 1e-5) -> Tuple[jax.Array, Dict]:
+                  cache: Dict, eps: float = 1e-5,
+                  zxbcdt: Optional[Sequence[float]] = None
+                  ) -> Tuple[jax.Array, Dict]:
     """Single-token step. x: [B, 1, D]; cache: {"conv": [B,K-1,C], "ssm": [B,H,P,N]}.
-    Conv shift + state update run as one fused decode kernel."""
+    Conv shift + state update run as one fused decode kernel.  ``zxbcdt``
+    as in :func:`mamba2_block`."""
     b = x.shape[0]
     di = s.d_inner(d_model)
     dt_ = x.dtype
@@ -136,12 +159,16 @@ def mamba2_decode(p: Dict, x: jax.Array, s: SSMConfig, d_model: int, *,
         z = xt @ p["wz"].astype(dt_)
         xbc = xt @ p["wxBC"].astype(dt_)
         dt_raw = xt @ p["wdt"].astype(dt_)
+        if zxbcdt is not None:
+            z, xbc, dt_raw = _scale_in_proj(z, xbc, dt_raw, zxbcdt, s,
+                                            d_model)
     y, conv_state, ssm_state = mamba2_decode_fused(
         cache["conv"], cache["ssm"], xbc, p["conv_w"], p["conv_b"],
         dt_raw, p["dt_bias"], p["A_log"], p["D"],
         n_groups=s.n_groups, d_state=s.d_state, headdim=s.headdim)
     y = y.reshape(b, di)
-    y = gated_rms_norm(y[:, None, :], z[:, None, :], p["norm_scale"], eps)[:, 0]
+    y = gated_rms_norm(y[:, None, :], z[:, None, :], p["norm_scale"], eps,
+                       groups=s.n_groups)[:, 0]
     with jax.named_scope("ssm_out_proj"):
         out = (y @ p["out_proj"].astype(dt_))[:, None, :]
     return out, {"conv": conv_state.astype(cache["conv"].dtype),

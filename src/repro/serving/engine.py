@@ -362,7 +362,13 @@ class ServingEngine:
                                                   "rope_len",
                                                   "with_sentinel"),
                                  donate_argnames=("cache",))
-        self._scatter = jax.jit(_scatter_group)
+        # a model that holds KV caches of max_seq rows a slot scatters
+        # into its slot cache in place: a second copy of it would not fit
+        # beside the weights at published widths (a KV-free model's cache
+        # is small, and its programs stay as they are)
+        self._scatter = jax.jit(
+            _scatter_group,
+            donate_argnums=(0,) if kv_cache_extent(cfg, max_seq) else ())
         self.kv_repeat = kv_repeat
         self.chunk_size = chunk_size or min(256, max_seq)
         self.preempt_after = preempt_after
@@ -494,6 +500,15 @@ class ServingEngine:
         self._m_queued_s = req_s.labels(state="queued")
         self._m_prefill_s = req_s.labels(state="prefill")
         self._m_decode_s = req_s.labels(state="decode")
+        kv_pos = m.counter(
+            "repro_attn_kv_positions_total",
+            "attention KV positions per prefill-chunk row and per decode "
+            "step of a live slot: the KV bucket the program holds (read) "
+            "and the positions live in it (live)")
+        self._m_kv_read = {ph: kv_pos.labels(phase=ph, what="read")
+                           for ph in ("prefill", "decode")}
+        self._m_kv_live = {ph: kv_pos.labels(phase=ph, what="live")
+                           for ph in ("prefill", "decode")}
         self._m_climbs = m.counter(
             "repro_bucket_climbs_total",
             "decode dispatches entering a deeper KV rung (each pays "
@@ -903,6 +918,14 @@ class ServingEngine:
             # ragged final chunks — recorded per (phase, bucket) with the
             # first dispatch of a (batch, bucket) combo segregated as a
             # compile sample (trace+compile must not poison steady state)
+            rows = info["valid_per_row"] > 0
+            if info["bucket"] is not None and rows.any():
+                # each row with tokens in the chunk attends the bucket;
+                # its live keys end where its chunk tokens end
+                self._m_kv_read["prefill"].inc(
+                    int(rows.sum()) * info["bucket"])
+                self._m_kv_live["prefill"].inc(int(
+                    (info["offset"] + info["valid_per_row"][rows]).sum()))
             if info["valid_tokens"] > 0:
                 tok_ms = dt_ms / info["valid_tokens"]
                 self.telemetry.record_latency(
@@ -1240,6 +1263,11 @@ class ServingEngine:
                         if r is not None]
             kv_bucket = clamped_bucket(max(live_pos) + kblk, self.kv_extent)
             self.buckets_used.add(kv_bucket)
+            # step i of the burst reads the bucket for every live slot,
+            # whose keys then span pos + i + 1 positions
+            self._m_kv_read["decode"].inc(len(live_pos) * kblk * kv_bucket)
+            self._m_kv_live["decode"].inc(
+                kblk * sum(live_pos) + len(live_pos) * kblk * (kblk + 1) // 2)
         # the first dispatch per bucket key (None included — archs without
         # KV buckets still compile on their first burst) pays trace+compile
         fresh_compile = kv_bucket not in self._decode_seen

@@ -34,9 +34,11 @@ Chunk/decode interleave contract (what ``ServingEngine`` relies on):
   on the valid length so padding never clobbers live window history).
   Rows past the real group (batch padded to a template size) are
   zero-length and therefore complete no-ops.
-* The group cache template is allocated once per retained batch size and
-  reused for every subsequent group (prefill is functional — the template
-  itself is never mutated).
+* For a KV-free model the group cache template is allocated once per
+  retained batch size and reused for every subsequent group (prefill is
+  functional — the template itself is never mutated).  A model with KV
+  caches of ``max_seq`` rows gets a fresh group cache per group, which
+  its chunk steps update in place (donated), so one group cache is live.
 
 Compiled-shape discipline: every chunk step lowers to the same
 ``[batch, chunk]`` program regardless of prompt length, so XLA compiles
@@ -107,18 +109,23 @@ def _make_chunk_step(cfg: ModelConfig, plan: Optional[ShardingPlan] = None):
 # repeated chunked_prefill calls must reuse the compiled program, not
 # re-trace.  kv_bucket and rope_len are static arguments: one compile per
 # bucket-ladder rung actually touched (rope_len is constant per serving
-# deployment).
-_STEP_CACHE: Dict[Tuple[ModelConfig, int, int, bool, str], Any] = {}
+# deployment).  ``donate`` jits a step that takes its input cache over (a
+# group cache of max_seq-row KV leaves is updated in place; see
+# ChunkedPrefill).
+_STEP_CACHE: Dict[Tuple[ModelConfig, int, int, bool, str, bool], Any] = {}
 
 
-def _jitted_chunk_step(cfg: ModelConfig, plan: Optional[ShardingPlan]):
+def _jitted_chunk_step(cfg: ModelConfig, plan: Optional[ShardingPlan],
+                       donate: bool = False):
     key = (cfg, plan.kv_repeat if plan else 1,
            plan.moe_groups if plan else 1, kdispatch.ring_buckets(),
-           kdispatch.get_backend())
+           kdispatch.get_backend(), donate)
     if key not in _STEP_CACHE:
         _STEP_CACHE[key] = jax.jit(_make_chunk_step(cfg, plan),
                                    static_argnames=("kv_bucket", "rope_len",
-                                                    "with_sentinel"))
+                                                    "with_sentinel"),
+                                   donate_argnames=("cache",) if donate
+                                   else ())
     return _STEP_CACHE[key]
 
 
@@ -253,7 +260,13 @@ class ChunkedPrefill:
         self.kv_buckets = self.kv_extent is not None
         # rolling caches span only their window: rope must cover max_seq
         self.rope_len = rope_len_for(cfg, max_seq)
-        self._step = _jitted_chunk_step(cfg, plan)
+        # KV leaves of max_seq rows: each group gets a fresh cache that its
+        # chunks update in place (a kept template plus a chunk's input and
+        # output would hold three group caches at once); KV-free models
+        # keep one template a batch size and functional chunk steps
+        self._fresh_cache = self.kv_extent is not None
+        self._step = _jitted_chunk_step(cfg, plan,
+                                        donate=self._fresh_cache)
         self._templates: Dict[int, Any] = {}
         self._group: Optional[Dict[str, Any]] = None
         # (batch, kv_bucket) combos this scheduler has dispatched: the
@@ -264,8 +277,8 @@ class ChunkedPrefill:
         # diverts one sample to the compile record, never poisons steady.)
         self._dispatched: set = set()
         # facts about the most recent step(), for the engine's telemetry:
-        # {"bucket", "valid_tokens", "valid_per_row", "class_tokens",
-        #  "fresh_compile"}
+        # {"bucket", "offset", "valid_tokens", "valid_per_row",
+        #  "class_tokens", "fresh_compile"}
         self.last_chunk: Optional[Dict[str, Any]] = None
         # optional shared MetricsRegistry (the engine passes its own)
         self._m_chunks = self._m_quar = self._m_rows = None
@@ -290,6 +303,9 @@ class ChunkedPrefill:
         return self._group["cache"]
 
     def _template(self, batch: int):
+        if self._fresh_cache:
+            return init_lm_cache(self.cfg, batch, self.max_seq,
+                                 kv_repeat=self.kv_repeat)
         if batch not in self._templates:
             self._templates[batch] = init_lm_cache(
                 self.cfg, batch, self.max_seq, kv_repeat=self.kv_repeat)
@@ -375,7 +391,7 @@ class ChunkedPrefill:
             if clens[r]:
                 cls = int(g["priorities"][r])
                 class_tokens[cls] = class_tokens.get(cls, 0) + int(clens[r])
-        self.last_chunk = {"bucket": kv_bucket,
+        self.last_chunk = {"bucket": kv_bucket, "offset": off,
                            "valid_tokens": int(clens.sum()),
                            "valid_per_row": np.asarray(clens),
                            "class_tokens": class_tokens,

@@ -26,8 +26,9 @@ from repro.kernels.decode_fused.kernel import mamba2_decode_fused_pallas
 from repro.kernels.flash.kernel import flash_attention_pallas
 from repro.kernels.ssd.kernel import ssd_pallas
 
-MAMBA2_ARCHS = ("mamba2-2.7b", "zamba2-2.7b")
+MAMBA2_ARCHS = ("mamba2-2.7b", "zamba2-2.7b", "falcon-h1-34b")
 SEQ = 4096          # the served max_seq
+LONG = 65536        # falcon-h1-34b's served max_seq: its top KV bucket
 SLOTS = 8
 CHUNK = 256         # the engine's prefill chunk
 
@@ -116,3 +117,28 @@ def test_split_k_decode_attention_compiles(one_chip):
                                                          valid_len=vl),
              one_chip, ((SLOTS, h, d), bf), ((SLOTS, kvh, 2 * SEQ, d), bf),
              ((SLOTS, kvh, 2 * SEQ, d), bf), ((SLOTS,), jnp.int32))
+
+
+def _falcon_attn():
+    a = get("falcon-h1-34b").attn
+    return a.n_heads, a.n_kv_heads, a.head_dim
+
+
+def test_flash_prefill_compiles_at_falcon_h1_widths(one_chip):
+    """GQA 5:1 at head 128, two rows of a 256-token chunk against the
+    65536-row top KV bucket of falcon-h1-34b's longdoc cell."""
+    h, kvh, d = _falcon_attn()
+    bf = jnp.bfloat16
+    _compile(lambda q, k, v, off: flash_attention_pallas(
+        q, k, v, causal=True, q_offset=off),
+        one_chip, ((2, h, CHUNK, d), bf), ((2, kvh, LONG, d), bf),
+        ((2, kvh, LONG, d), bf), ((2,), jnp.int32))
+
+
+def test_split_k_decode_attention_compiles_at_falcon_h1_widths(one_chip):
+    h, kvh, d = _falcon_attn()
+    bf = jnp.bfloat16
+    _compile(lambda q, k, v, vl: decode_attention_pallas(q, k, v,
+                                                         valid_len=vl),
+             one_chip, ((2, h, d), bf), ((2, kvh, LONG, d), bf),
+             ((2, kvh, LONG, d), bf), ((2,), jnp.int32))
